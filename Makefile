@@ -44,7 +44,8 @@ bench-smoke:
 # so bucket warmup, the zero-recompile invariant, and the telemetry digest
 # stay exercised per-PR.  Results go to .cache/, never to BENCH_serve.json.
 bench-serve-smoke:
-	BENCH_SMOKE=1 BENCH_Q=32 PYTHONPATH=src timeout 420 \
+	XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+		BENCH_SMOKE=1 BENCH_Q=32 PYTHONPATH=src timeout 420 \
 		python -m benchmarks.run --only serve
 
 # CI tier: shrunk two-phase shifting trace through the autotuned frontend —

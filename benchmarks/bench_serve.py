@@ -4,9 +4,9 @@ One ragged request trace (log-uniform sizes up to the top bucket) replayed
 through ``repro.serve.ServeFrontend`` against both backends:
 
 * ``serve_single``  — one ``AnnIndex`` in-process;
-* ``serve_sharded`` — a ``ShardedAnnIndex`` over 8 host devices, run in a
-  subprocess (``--xla_force_host_platform_device_count`` must be set before
-  jax initializes, which the parent process already did).
+* ``serve_sharded`` — a ``ShardedAnnIndex`` over every device of this
+  process, in-process (on the CPU, set
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for N shards).
 
 Acceptance (ISSUE 5): per-bucket p50/p95/p99 latency + QPS for both
 backends, and ``recompiles_after_warmup == 0`` across the ragged trace —
@@ -15,17 +15,15 @@ warmup.  ``BENCH_SMOKE=1`` shrinks sizes and diverts the JSON to .cache/.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
+import jax
 import numpy as np
 
 from benchmarks.common import (SMOKE, dataset, cached_index, emit,
                                persist_bench, smoke_scale)
+from repro.core.sharded_index import ShardedAnnIndex, shard_dataset
 from repro.core.spec import SearchSpec
 from repro.data.vectors import exact_ground_truth, recall_at_k
+from repro.launch.mesh import make_local_mesh
 from repro.serve import ServeFrontend
 
 BUCKETS = (1, 4, 8) if SMOKE else (1, 8, 32, 64)
@@ -94,48 +92,24 @@ def serve_single():
     return summ
 
 
-_SHARDED_CHILD = r"""
-import json
-import numpy as np
-from benchmarks import bench_serve as BS
-from benchmarks.common import dataset, smoke_scale
-from repro.core.sharded_index import shard_dataset, ShardedAnnIndex
-from repro.core.spec import SearchSpec
-from repro.data.vectors import exact_ground_truth
-from repro.launch.mesh import make_local_mesh
-import jax
-
-n_dev = len(jax.devices())
-ds = dataset("sift-synth", n_base=smoke_scale(4000, 600))
-gt = exact_ground_truth(ds, k=10)
-arrays = shard_dataset(ds.base, n_shards=n_dev, graph="hnsw",
-                       m=smoke_scale(16, 8), efc=smoke_scale(96, 48))
-mesh = make_local_mesh(n_dev, "shards")
-spec = SearchSpec(efs=64, k=10, router="crouting", max_hops=2048)
-idx = ShardedAnnIndex(arrays, mesh, spec=spec)
-summ = BS._run_trace(idx, spec, ds, gt)
-summ["n_base"] = int(ds.base.shape[0])
-summ["n_shards"] = n_dev
-print("RESULT " + json.dumps(summ))
-"""
-
-
 def serve_sharded():
-    """Sharded backend over 8 host devices (subprocess: the device-count
-    flag must be set before jax initializes)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        " --xla_force_host_platform_device_count=" +
-                        ("4" if SMOKE else "8")).strip()
-    env.setdefault("PYTHONPATH", "src")
-    r = subprocess.run([sys.executable, "-c", _SHARDED_CHILD], env=env,
-                       capture_output=True, text=True,
-                       cwd=os.path.join(os.path.dirname(__file__), ".."),
-                       timeout=3000)
-    if r.returncode != 0:
-        raise RuntimeError(f"sharded serve child failed:\n{r.stderr[-3000:]}")
-    line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
-    summ = json.loads(line[len("RESULT "):])
+    """Sharded backend over every device this process has, in-process (one
+    process per chip; on the CPU the caller sets
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before start)."""
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        raise RuntimeError(
+            f"serve_sharded needs >= 2 devices, this process has {n_dev}; on "
+            "the CPU set XLA_FLAGS=--xla_force_host_platform_device_count=8")
+    ds = dataset("sift-synth", n_base=smoke_scale(4000, 600))
+    gt = exact_ground_truth(ds, k=10)
+    arrays = shard_dataset(ds.base, n_shards=n_dev, graph="hnsw",
+                           m=smoke_scale(16, 8), efc=smoke_scale(96, 48))
+    mesh = make_local_mesh(n_dev, "shards")
+    spec = SearchSpec(efs=64, k=10, router="crouting", max_hops=2048)
+    summ = _run_trace(ShardedAnnIndex(arrays, mesh, spec=spec), spec, ds, gt)
+    summ["n_base"] = int(ds.base.shape[0])
+    summ["n_shards"] = n_dev
     emit("serve_sharded", 0.0,
          {"qps": summ["qps"], "p50_ms": summ["latency"]["p50_ms"],
           "p99_ms": summ["latency"]["p99_ms"],

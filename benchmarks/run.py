@@ -18,6 +18,7 @@ from benchmarks import bench_mutate as M
 from benchmarks import bench_recovery as D
 from benchmarks import bench_roofline as R
 from benchmarks import bench_serve as S
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = [
     ("engine_beam_sweep", E.engine_beam_sweep),
@@ -53,6 +54,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="substring filter")
     args = ap.parse_args()
+    print(f"# compile cache: {enable_compile_cache()}")
     failed, ran = [], []
     for name, fn in BENCHES:
         if args.only and args.only not in name:

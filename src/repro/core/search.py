@@ -29,9 +29,9 @@ Re-derivation of the paper's Algorithm 1/2 for fixed-shape SPMD execution
       attribution).
 
 Pad-row sentinel convention (repo-wide): ``graph_device_arrays`` appends one
-zero vector at row index N; every masked/pruned/out-of-range lane gathers
-that row (``ops.gather_distance_pruned`` remaps to the table's last row).
-Pool slots holding no candidate carry id N and distance +inf.
+zero vector at row index N; every masked/pruned/out-of-range lane of the
+jnp engine gathers that row (the Pallas kernels issue no DMA for such a
+lane).  Pool slots holding no candidate carry id N and distance +inf.
 
 Two-stage quantized distances (``SearchSpec.estimate``, PAPERS.md: VSAG /
 Probabilistic Routing): with ``estimate="sq8"`` or ``"both"`` the surviving
@@ -103,8 +103,8 @@ def graph_device_arrays(g: GraphIndex, with_sq8: bool = False) -> Dict[str, Any]
     Pad-row convention: row N of ``vectors`` (an all-zero vector, norm slot 1)
     is THE sentinel every masked lane resolves to — adjacency pad slots point
     at it, dead beam slots expand it (its neighbor list is all-pad), and the
-    Pallas gather kernels remap pruned lanes to it so the skipped DMA is
-    de-duplicated.  Pool slots holding no candidate carry id N.
+    jnp engine gathers it for pruned lanes.  Pool slots holding no candidate
+    carry id N.
 
     ``with_sq8`` adds the quantized companion tables (same pad-row
     convention: row N encodes the zero vector).  The default path skips them
@@ -157,6 +157,25 @@ def ensure_sq8_arrays(g: GraphIndex, arrays: Dict[str, Any]) -> Dict[str, Any]:
         arrays["sq8_lo"] = jnp.asarray(qp.lo)
         arrays["sq8_scale"] = jnp.asarray(qp.scale)
         arrays["sq8_eps"] = jnp.asarray(qp.eps)
+    return arrays
+
+
+def ensure_kernel_tables(arrays: Dict[str, Any],
+                         put=jnp.asarray) -> Dict[str, Any]:
+    """Add the Pallas engines' table layouts to a packed arrays dict
+    (idempotent): the fp32 rows as a ``row_table`` and, when the SQ8 tables
+    exist, the codes packed by ``code_words``.  Converted once on the host
+    and placed by ``put``; at d == 128 the row table is the ``vectors``
+    array itself.  Tables may carry leading axes (per-shard stacks)."""
+    from repro.kernels import ops
+
+    if "vectors_rows" not in arrays:
+        v = arrays["vectors"]
+        arrays["vectors_rows"] = v if v.shape[-1] == ops.LANES else \
+            put(ops.row_table(np.asarray(v)))
+    if "sq8_codes" in arrays and "sq8_words" not in arrays:
+        arrays["sq8_words"] = put(
+            ops.code_words(np.asarray(arrays["sq8_codes"])))
     return arrays
 
 
@@ -321,6 +340,13 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
     if use_pallas:
         from repro.kernels import ops
 
+        # kernel table layouts, converted once by ensure_kernel_tables
+        assert "vectors_rows" in arrays, \
+            "pallas engines read the ensure_kernel_tables layouts"
+        vec_rows = arrays["vectors_rows"]
+        if sq8_on:
+            code_words = arrays["sq8_words"]
+
     nq = (jnp.linalg.norm(queries, axis=1) if metric != "l2"
           else jnp.ones((B,), jnp.float32))
 
@@ -331,7 +357,7 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
         idx = jnp.where(mask, ids, n).astype(jnp.int32)
         if use_pallas:
             eu2 = ops.gather_distance_pruned(
-                idx, (~mask).astype(jnp.int8), queries, arrays["vectors"])
+                idx, (~mask).astype(jnp.int8), queries, vec_rows)
             r = _eu2_to_rank(eu2, nq[:, None], arrays["norms"][idx], metric)
         else:
             r = _rank_tile(queries, arrays["vectors"][idx], metric)
@@ -516,7 +542,7 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
             # row DMA on this path (that is stage 2's job, in _exact_rerank)
             if use_pallas:
                 ad2, lb2 = ops.sq8_estimate(
-                    nbrs, queries, compute, arrays["sq8_codes"],
+                    nbrs, queries, compute, code_words,
                     arrays["sq8_lo"], arrays["sq8_scale"], arrays["sq8_eps"])
             else:
                 from repro.quant.sq8 import sq8_dequantize_rows, sq8_estimate
@@ -542,7 +568,7 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
             if engine == "pallas":
                 d2eu, prune8 = ops.fused_expand(
                     nbrs, queries, ed, dcq_l, bound2, ct_eff,
-                    arrays["vectors"], eval_mask=compute,
+                    vec_rows, eval_mask=compute,
                     prune_eligible=try_prune if kernel_prunes
                     else jnp.zeros_like(try_prune))
                 if kernel_prunes:
@@ -555,7 +581,7 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
             elif engine == "pallas_unfused":
                 d2eu = ops.gather_distance_pruned(
                     jnp.where(compute, nbrs, n), (~compute).astype(jnp.int8),
-                    queries, arrays["vectors"])
+                    queries, vec_rows)
                 exact = _eu2_to_rank(d2eu, nq[:, None], nx, metric)
             else:
                 gathered = arrays["vectors"][jnp.where(compute, nbrs, n)]
@@ -661,6 +687,24 @@ _ENGINE_CACHE: "dict[tuple, tuple]" = {}
 _ENGINE_CACHE_MAX = 16
 
 
+class _BoundEngine:
+    """A jitted engine with its graph's device tables bound as *arguments*.
+
+    A closed-over array is baked into the executable as a constant — one
+    copy of every table per compiled batch shape, in the compile and on the
+    device; an argument is shared.  Called as ``fn(queries, cos_theta[,
+    tombstone])``; ``_cache_size`` counts the compiled executables."""
+
+    def __init__(self, jitted, tables):
+        self._jitted, self._tables = jitted, tables
+
+    def __call__(self, *args):
+        return self._jitted(self._tables, *args)
+
+    def _cache_size(self) -> int:
+        return self._jitted._cache_size()
+
+
 def _purge_dead_cache_entries():
     """Drop every cache entry tied to a collected graph.
 
@@ -721,21 +765,26 @@ def build_search_fn(g: GraphIndex, cfg: SearchSpec, tombstones: bool = False):
         # lazily upgrade the (shared) cached dict: exact-only graphs never
         # pay the encode pass or the extra device tables
         ensure_sq8_arrays(g, arrays)
+    if cfg.engine != "jnp":
+        ensure_kernel_tables(arrays)
     # router companion tables (e.g. finger signatures) upgrade it the same
     # lazy way the first time the router is configured for this graph
     rt.prepare(g, arrays)
 
+    n = arrays["n"]
+    tables = {k: v for k, v in arrays.items() if k != "n"}
     if tombstones:
         @jax.jit
-        def run(queries, cos_theta, tombstone):
-            queries = queries.astype(jnp.float32)
-            return _search_batch(arrays, queries, cos_theta, cfg,
+        def run(tables, queries, cos_theta, tombstone):
+            return _search_batch({**tables, "n": n},
+                                 queries.astype(jnp.float32), cos_theta, cfg,
                                  tombstone=tombstone)
     else:
         @jax.jit
-        def run(queries, cos_theta):
-            queries = queries.astype(jnp.float32)
-            return _search_batch(arrays, queries, cos_theta, cfg)
+        def run(tables, queries, cos_theta):
+            return _search_batch({**tables, "n": n},
+                                 queries.astype(jnp.float32), cos_theta, cfg)
+    run = _BoundEngine(run, tables)
 
     while len(_ENGINE_CACHE) >= _ENGINE_CACHE_MAX:
         _ENGINE_CACHE.pop(next(iter(_ENGINE_CACHE)))

@@ -24,13 +24,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import distances as D
 from repro.core.angles import sample_angle_profile
 from repro.core.graph import GraphIndex
 from repro.core.routers import get_router
-from repro.core.search import _search_batch
+from repro.core.search import _search_batch, ensure_kernel_tables
 from repro.core.spec import SearchSpec, SearchStats, resolve_search_spec
 from repro.fault import failpoints as fault
 from repro.quant import sq8 as SQ
@@ -54,6 +53,24 @@ class ShardedIndexArrays:
     sq8_lo: np.ndarray = None      # [S, d]
     sq8_scale: np.ndarray = None   # [S, d]
     sq8_eps: np.ndarray = None     # [S, d]
+    # Pallas engine layouts (search.ensure_kernel_tables), filled on first
+    # use by ShardedAnnIndex
+    vectors_rows: np.ndarray = None  # [S, (ns+1)*ceil(d/128), 128] f32
+    sq8_words: np.ndarray = None     # [S, (ns+1)*ceil(d/512), 128] int32
+
+
+# per-shard tables of the serve step, in argument order
+DATA_TABLES = ("vectors", "neighbors", "edge_eu", "norms", "entries",
+               "offsets", "sq8_codes", "sq8_lo", "sq8_scale", "sq8_eps")
+
+
+def step_tables(cfg: SearchSpec) -> Tuple[str, ...]:
+    """Names of the per-shard tables ``cfg``'s serve step takes, in order:
+    the Pallas engines add the kernel layouts they read."""
+    if cfg.engine == "jnp":
+        return DATA_TABLES
+    sq8 = ("sq8_words",) if cfg.estimate in ("sq8", "both") else ()
+    return DATA_TABLES + ("vectors_rows",) + sq8
 
 
 def shard_dataset(base: np.ndarray, n_shards: int, metric: str = "l2",
@@ -134,7 +151,8 @@ def make_serve_step(mesh: Mesh, cfg: SearchSpec, ns: int,
 
     shard_axes: mesh axes flattened into the shard dimension (default: all).
     Returns (serve_step, in_shardings, out_shardings) ready for jit/lower.
-    The step takes ``(*10 data arrays, queries, cos_theta, valid)`` where
+    The step takes ``(*tables, queries, cos_theta, valid)``, the tables
+    named by ``step_tables(cfg)`` each with a leading shard axis, where
     ``valid`` [B] bool marks the real lanes of a bucket-padded batch
     (padded lanes are born done inside the engine and contribute zero to
     every counter — see ``_search_batch``).
@@ -151,22 +169,20 @@ def make_serve_step(mesh: Mesh, cfg: SearchSpec, ns: int,
     axes = tuple(shard_axes or mesh.axis_names)
     extra_names = get_router(cfg.router).extra_counters
     kk = cfg.efs              # merge width; k slices host-side
+    names = step_tables(cfg)
+    nt = len(names)
 
-    def local_search(vectors, neighbors, edge_eu, norms, entries, offsets,
-                     sq8_codes, sq8_lo, sq8_scale, sq8_eps,
-                     queries, cos_theta, valid):
+    def local_search(*args):
+        queries, cos_theta, valid = args[nt:]
         # shard_map gives the local shard with a leading axis of size 1
-        arrays = {
-            "vectors": vectors[0], "neighbors": neighbors[0],
-            "edge_eu": edge_eu[0], "norms": norms[0],
-            "entry": entries[0], "n": ns,
-            "sq8_codes": sq8_codes[0], "sq8_lo": sq8_lo[0],
-            "sq8_scale": sq8_scale[0], "sq8_eps": sq8_eps[0],
-        }
+        arrays = {nm: a[0] for nm, a in zip(names, args[:nt])}
+        arrays["entry"] = arrays.pop("entries")
+        offset = arrays.pop("offsets")
+        arrays["n"] = ns
         res = _search_batch(arrays, queries, cos_theta, cfg, valid=valid)
         loc_d, loc_i = res.dists[:, :kk], res.ids[:, :kk]
         # int32 global ids (enable_x64 is off; fine below 2^31 vectors/shard set)
-        glob_i = jnp.where(loc_i < ns, loc_i + offsets[0].astype(jnp.int32), -1)
+        glob_i = jnp.where(loc_i < ns, loc_i + offset.astype(jnp.int32), -1)
         # merge: gather per-shard pools along the shard axis, then re-top-k
         all_d = jax.lax.all_gather(loc_d, axes, tiled=False)   # [S, B, efs]
         all_i = jax.lax.all_gather(glob_i, axes, tiled=False)
@@ -187,14 +203,14 @@ def make_serve_step(mesh: Mesh, cfg: SearchSpec, ns: int,
     pspec_data = P(axes)      # shard leading axis over all shard axes
     pspec_rep = P()           # queries / cos_theta / valid replicated
 
-    serve = shard_map(
+    serve = jax.shard_map(
         local_search, mesh=mesh,
-        in_specs=(pspec_data,) * 10 + (pspec_rep,) * 3,
+        in_specs=(pspec_data,) * nt + (pspec_rep,) * 3,
         out_specs=(pspec_rep, pspec_rep, pspec_rep),
-        check_rep=False,
+        check_vma=False,
     )
     in_sh = tuple(NamedSharding(mesh, s) for s in
-                  (pspec_data,) * 10 + (pspec_rep,) * 3)
+                  (pspec_data,) * nt + (pspec_rep,) * 3)
     out_sh = tuple(NamedSharding(mesh, s) for s in (pspec_rep,) * 3)
     return serve, in_sh, out_sh
 
@@ -238,7 +254,7 @@ class ShardedAnnIndex:
             arrays = _backfill_sq8(arrays)
             self.arrays = arrays
         self._steps = {}       # canonical spec -> jitted serve step
-        self._placed = None    # device-placed data arrays (fixed shardings)
+        self._placed = {}      # table name -> device-placed stacked array
         self._step(spec)       # validate + pre-jit the construction spec
 
     def _step(self, spec: SearchSpec):
@@ -255,15 +271,28 @@ class ShardedAnnIndex:
                 "carry yet; use the single-index path")
         serve, in_sh, _ = make_serve_step(self.mesh, key, self.arrays.ns)
         fn = jax.jit(serve, in_shardings=in_sh)
-        if self._placed is None:
-            dev = lambda a, sh: jax.device_put(a, sh)
-            self._placed = tuple(
-                dev(getattr(self.arrays, f), s) for f, s in
-                zip(("vectors", "neighbors", "edge_eu", "norms", "entries",
-                     "offsets", "sq8_codes", "sq8_lo", "sq8_scale",
-                     "sq8_eps"), in_sh[:10]))
+        self._place(step_tables(key), in_sh[0])
         self._steps[key] = fn
         return fn
+
+    def _place(self, names, sharding):
+        """Put the named per-shard tables on the mesh, each once.  The
+        Pallas layouts are converted on the host first, by the same
+        ``ensure_kernel_tables`` as the single-index engine."""
+        a = self.arrays
+        if a.vectors_rows is None and "vectors_rows" in names:
+            t = ensure_kernel_tables({"vectors": a.vectors,
+                                      "sq8_codes": a.sq8_codes}, put=np.asarray)
+            a = self.arrays = dataclasses.replace(
+                a, vectors_rows=t["vectors_rows"], sq8_words=t["sq8_words"])
+        for f in names:
+            if f in self._placed:
+                continue
+            t = getattr(a, f)
+            # at d == 128 the row table is the vectors array: one device copy
+            self._placed[f] = (
+                self._placed["vectors"] if f != "vectors" and t is a.vectors
+                else jax.device_put(t, sharding))
 
     def search(self, queries: np.ndarray, spec=None, *,
                valid: Optional[np.ndarray] = None
@@ -295,7 +324,8 @@ class ShardedAnnIndex:
             ct = self.arrays.cos_theta
         v = (jnp.ones((q.shape[0],), bool) if valid is None
              else jnp.asarray(valid, bool))
-        d, i, sv = fn(*self._placed, jnp.asarray(q),
+        d, i, sv = fn(*(self._placed[f] for f in step_tables(spec)),
+                      jnp.asarray(q),
                       jnp.asarray(ct, jnp.float32), v)
         sv = np.asarray(sv)
         extra_names = get_router(spec.router).extra_counters

@@ -37,7 +37,7 @@ def _prune_kernel(ed_ref, dcq_ref, bound2_ref, valid_ref, ct_ref, est_ref, mask_
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def crouting_prune_pallas(ed, dcq, bound2, valid, cos_theta, *,
-                          bb: int = 8, interpret: bool = True):
+                          bb: int, interpret: bool):
     """ed [B, M], dcq [B, M], bound2 [B, M], valid [B, M] int8, cos_theta
     scalar -> (est2 [B, M] f32, prune [B, M] int8).
 

@@ -42,16 +42,15 @@ def _dist_kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, *, n_d_steps: int, mode: s
     @pl.when(k == n_d_steps - 1)
     def _fin():
         if mode == "l2":
-            o_ref[...] = jnp.maximum(
-                o_ref[...] + qn_ref[...].reshape(-1, 1) + xn_ref[...].reshape(1, -1),
-                0.0)
+            o_ref[...] = jnp.maximum(o_ref[...] + qn_ref[...] + xn_ref[...],
+                                     0.0)
         else:
             o_ref[...] += 1.0  # IPDist = 1 - <q, x>
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bc", "bd", "mode", "interpret"))
 def l2_distance_pallas(q, x, *, bq: int = 128, bc: int = 256, bd: int = 512,
-                       mode: str = "l2", interpret: bool = True):
+                       mode: str = "l2", interpret: bool):
     """q [Q, d], x [C, d] -> dist [Q, C] (squared L2, or IP distance)."""
     Q, d = q.shape
     C = x.shape[0]
@@ -59,8 +58,8 @@ def l2_distance_pallas(q, x, *, bq: int = 128, bc: int = 256, bd: int = 512,
     assert Q % bq == 0 and C % bc == 0 and d % bd == 0, (
         "pad inputs to block multiples (ops.l2_distance handles padding)")
     n_d = d // bd
-    qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=-1)
-    xn = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1)
+    qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=-1, keepdims=True)  # [Q, 1]
+    xn = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1)[None, :]        # [1, C]
     grid = (Q // bq, C // bc, n_d)
     return pl.pallas_call(
         functools.partial(_dist_kernel, n_d_steps=n_d, mode=mode),
@@ -68,8 +67,8 @@ def l2_distance_pallas(q, x, *, bq: int = 128, bc: int = 256, bd: int = 512,
         in_specs=[
             pl.BlockSpec((bq, bd), lambda i, j, k: (i, k)),
             pl.BlockSpec((bc, bd), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bq,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bc,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bq, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bc), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bq, bc), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Q, C), jnp.float32),

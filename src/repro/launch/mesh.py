@@ -9,17 +9,14 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kw(n_axes: int) -> dict:
-    """jax.sharding.AxisType landed after 0.4.37; omit the kwarg when the
-    installed jax predates it (Auto is the default there anyway)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def data_axes(mesh) -> tuple:
@@ -30,4 +27,4 @@ def data_axes(mesh) -> tuple:
 def make_local_mesh(n: int = 1, name: str = "data"):
     """Mesh over whatever devices exist (tests / examples)."""
     n = min(n, len(jax.devices()))
-    return jax.make_mesh((n,), (name,), **_axis_type_kw(1))
+    return jax.make_mesh((n,), (name,), axis_types=_auto(1))

@@ -26,9 +26,10 @@ import jax
 
 from repro.core.index import AnnIndex
 from repro.core.sharded_index import shard_dataset, ShardedAnnIndex
-from repro.core.spec import SearchSpec
+from repro.core.spec import ENGINES, ESTIMATES, SearchSpec
 from repro.data.vectors import make_dataset, exact_ground_truth, recall_at_k
 from repro.fault import RetryPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.serve import QueueFull, ServeFrontend
 
@@ -46,6 +47,11 @@ def main():
     ap.add_argument("--dim", type=int, default=128)
     ap.add_argument("--graph", default="hnsw", choices=["hnsw", "nsg"])
     ap.add_argument("--router", default="crouting")
+    ap.add_argument("--engine", default="jnp", choices=ENGINES,
+                    help="hop-loop engine: jnp reference or the Pallas "
+                         "kernels (compiled on TPU, interpreted on CPU)")
+    ap.add_argument("--estimate", default="exact", choices=ESTIMATES,
+                    help="distance strategy (SearchSpec.estimate)")
     ap.add_argument("--efs", type=int, default=100)
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--requests", type=int, default=100)
@@ -71,6 +77,7 @@ def main():
     ap.add_argument("--efc", type=int, default=128)
     args = ap.parse_args()
     buckets = tuple(int(b) for b in args.buckets.split(","))
+    print(f"compile cache: {enable_compile_cache()}")
 
     n_dev = len(jax.devices())
     print(f"devices: {n_dev}")
@@ -78,6 +85,7 @@ def main():
     ds = make_dataset(n_base=args.n_base, n_query=int(sizes.sum()),
                       dim=args.dim, seed=0)
     spec = SearchSpec(efs=args.efs, k=args.k, router=args.router,
+                      engine=args.engine, estimate=args.estimate,
                       max_hops=2048)
 
     t0 = time.time()

@@ -119,7 +119,6 @@ def table_parallel_lookup(tables, ids):
     the device count stay replicated (they are tiny).  Falls back to plain
     takes without a mesh (smoke tests / single device)."""
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     try:
         from jax._src.mesh import thread_resources
@@ -162,8 +161,8 @@ def table_parallel_lookup(tables, ids):
     in_specs = ([P(axes, None) if b else P(None, None) for b in big],
                 P(None, None))
     out_specs = tuple(P(None, None) for _ in tables)
-    return list(shard_map(local, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)(tables, ids))
+    return list(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                              out_specs=out_specs, check_vma=False)(tables, ids))
 
 
 def dot_interaction(vectors):

@@ -135,8 +135,9 @@ def test_fused_expand_masks_and_per_lane():
 
 
 def test_gather_distance_pruned_uses_pad_row_sentinel():
-    """Pruned lanes must remap to the table's LAST row (the engine pad row),
-    not row 0 — unified sentinel convention (graph_device_arrays)."""
+    """Lanes naming the table's LAST row (the engine pad row,
+    graph_device_arrays) compute normally when unpruned; pruned lanes skip
+    their row DMA and report +inf."""
     table = jnp.asarray(RNG.normal(size=(32, 8)), jnp.float32)
     qs = jnp.asarray(RNG.normal(size=(2, 8)), jnp.float32)
     idx = jnp.full((2, 4), 31, jnp.int32)   # all lanes point at the pad row

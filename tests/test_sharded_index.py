@@ -157,3 +157,60 @@ def test_sharded_index_subprocess():
     assert out["padded_counters_zero"], out
     assert out["frontend_matches_direct"], out
     assert out["frontend_recompiles"] == 0, out
+
+
+PALLAS_SCRIPT = r"""
+import json
+import numpy as np
+from repro.core.sharded_index import shard_dataset, ShardedAnnIndex
+from repro.core.spec import SearchSpec
+from repro.data.vectors import make_dataset
+from repro.launch.mesh import make_local_mesh
+
+mesh = make_local_mesh(4, "shards")
+ds = make_dataset(n_base=800, n_query=16, dim=48, n_clusters=8, seed=0)
+arrays = shard_dataset(ds.base, n_shards=4, graph="hnsw", m=8, efc=32)
+out = {}
+for est in ("exact", "sq8"):
+    res = {}
+    for eng in ("jnp", "pallas"):
+        spec = SearchSpec(k=10, efs=24, router="crouting", engine=eng,
+                          estimate=est, max_hops=512)
+        idx = ShardedAnnIndex(arrays, mesh, spec=spec)
+        ids, d, st = idx.search(ds.queries)
+        res[eng] = (ids.tolist(), int(st.dist_calls), int(st.sq8_calls))
+    out[est] = res
+out["placed"] = {f: list(a.shape) for f, a in idx._placed.items()}
+# d == 128: the row table is the vectors table, placed once
+ds128 = make_dataset(n_base=400, n_query=4, dim=128, n_clusters=4, seed=1)
+a128 = shard_dataset(ds128.base, n_shards=4, graph="hnsw", m=8, efc=32)
+i128 = ShardedAnnIndex(a128, mesh, spec=SearchSpec(k=10, efs=16,
+                                                   engine="pallas"))
+out["aliased"] = i128._placed["vectors_rows"] is i128._placed["vectors"]
+out["jnp_tables"] = sorted(ShardedAnnIndex(a128, mesh)._placed)
+print("RESULT " + json.dumps(out))
+"""
+
+
+def test_sharded_pallas_engine_reads_placed_kernel_tables():
+    """The sharded serve step feeds the Pallas engines the kernel layouts
+    converted once on the host and placed with the shards (d = 48 is padded
+    to a 128-lane row table, SQ8 codes packed into int32 words), and agrees
+    with the jnp engine on ids and counters."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    r = subprocess.run([sys.executable, "-c", PALLAS_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
+    out = json.loads(line[len("RESULT "):])
+    for est in ("exact", "sq8"):
+        assert out[est]["jnp"] == out[est]["pallas"], est
+    assert out["sq8"]["pallas"][2] > 0
+    ns1 = 200 + 1                       # 800 rows over 4 shards + pad row
+    assert out["placed"]["vectors_rows"] == [4, ns1, 128]
+    assert out["placed"]["sq8_words"] == [4, ns1, 128]
+    assert out["aliased"]
+    assert "vectors_rows" not in out["jnp_tables"]
