@@ -1,6 +1,6 @@
 """Kernel micro-benchmarks (interpret-mode timings are NOT TPU performance —
-they validate call overhead and feed the us_per_call column; TPU numbers come
-from the §Roofline dry-run terms)."""
+they validate call overhead and feed the us_per_call column; TPU kernel times
+come from the chip benchmark's profiler trace, ``chipbench/``)."""
 from __future__ import annotations
 
 import numpy as np

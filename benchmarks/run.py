@@ -1,8 +1,8 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
 """Benchmark harness: ``PYTHONPATH=src python -m benchmarks.run [--only X]``.
 
-Covers every table/figure of the paper (DESIGN.md §8) plus kernel micros and
-the dry-run roofline table.  Scale via BENCH_N / BENCH_Q env vars.
+Covers every table/figure of the paper (DESIGN.md §8) plus kernel micros.
+Scale via BENCH_N / BENCH_Q env vars.
 """
 import argparse
 import sys
@@ -16,7 +16,6 @@ from benchmarks import bench_paper as P
 from benchmarks import bench_kernels as K
 from benchmarks import bench_mutate as M
 from benchmarks import bench_recovery as D
-from benchmarks import bench_roofline as R
 from benchmarks import bench_serve as S
 from repro.launch.compile_cache import enable_compile_cache
 
@@ -46,7 +45,6 @@ BENCHES = [
     ("table6_7_construction", P.table6_7_construction),
     ("fig18_strategies", P.fig18_strategies),
     ("kernels_micro", K.kernels_micro),
-    ("roofline_table", R.roofline_table),
 ]
 
 

@@ -46,6 +46,7 @@ from repro.core.hnsw import build_hnsw
 from repro.core.nsg import build_nsg
 from repro.core.knn_graph import build_knn_graph
 from repro.core.search import SearchResult, build_search_fn
+from repro.core.spans import span
 from repro.core.spec import SearchSpec, SearchStats, resolve_search_spec
 
 GRAPH_BUILDERS = {"hnsw": build_hnsw, "nsg": build_nsg, "knn": build_knn_graph}
@@ -94,43 +95,50 @@ class AnnIndex:
         routers (which never read the threshold) keep the ``0.0``
         placeholder.  Slots with no result carry id -1 and distance +inf.
         Anything other than a ``SearchSpec`` (or ``None``) raises
-        ``TypeError``.
+        ``TypeError``.  Its phases are host spans (``core/spans.py``):
+        ``index.prepare``, ``index.launch`` and ``index.fetch``, the copy
+        of the results to the host.
         """
         import jax.numpy as jnp
 
         from repro.core.routers import get_router
 
-        spec = resolve_search_spec(spec, DEFAULT_SEARCH, "AnnIndex.search")
-        queries = D.preprocess_vectors(
-            np.ascontiguousarray(queries, np.float32), self.graph.metric)
-        cos_theta = spec.cos_theta
-        if cos_theta is None:
-            if self.profile is not None:
-                cos_theta = self.profile.cos_theta_star
-            elif get_router(spec.router).prunes:
-                raise ValueError(
-                    f"router {spec.router!r} prunes on the angle threshold, "
-                    "but this index was built with profile=False and the "
-                    "spec carries no explicit cos_theta — the old fallback "
-                    "of cos_theta=0.0 silently pruned at theta*=90deg. "
-                    "Build with profile=True, or set SearchSpec.cos_theta.")
-            else:
-                cos_theta = 0.0   # never read by a non-pruning router
-        k = spec.k
-        cfg = dataclasses.replace(
-            spec, efs=max(spec.efs, k), metric=self.graph.metric,
-            use_hierarchy=self.graph.upper_neighbors is not None)
-        _, fn = self._engine(cfg)
-        res: SearchResult = fn(jnp.asarray(queries),
-                               jnp.asarray(cos_theta, jnp.float32))
-        ids = np.asarray(res.ids[:, :k]).astype(np.int64)
-        dists = np.array(res.dists[:, :k])
-        # empty slots resolve to the pad row: mask BOTH columns (an id of -1
-        # must never ship with the pad row's finite distance)
-        pad = ids >= self.graph.n
-        ids[pad] = -1
-        dists[pad] = np.inf
-        return ids, dists, SearchStats.from_result(res, router=spec.router)
+        with span("index.prepare"):
+            spec = resolve_search_spec(spec, DEFAULT_SEARCH, "AnnIndex.search")
+            queries = D.preprocess_vectors(
+                np.ascontiguousarray(queries, np.float32), self.graph.metric)
+            cos_theta = spec.cos_theta
+            if cos_theta is None:
+                if self.profile is not None:
+                    cos_theta = self.profile.cos_theta_star
+                elif get_router(spec.router).prunes:
+                    raise ValueError(
+                        f"router {spec.router!r} prunes on the angle threshold, "
+                        "but this index was built with profile=False and the "
+                        "spec carries no explicit cos_theta — the old fallback "
+                        "of cos_theta=0.0 silently pruned at theta*=90deg. "
+                        "Build with profile=True, or set SearchSpec.cos_theta.")
+                else:
+                    cos_theta = 0.0   # never read by a non-pruning router
+            k = spec.k
+            cfg = dataclasses.replace(
+                spec, efs=max(spec.efs, k), metric=self.graph.metric,
+                use_hierarchy=self.graph.upper_neighbors is not None)
+            _, fn = self._engine(cfg)
+            q_dev = jnp.asarray(queries)
+            ct_dev = jnp.asarray(cos_theta, jnp.float32)
+        with span("index.launch"):
+            res: SearchResult = fn(q_dev, ct_dev)
+        with span("index.fetch"):
+            ids = np.asarray(res.ids[:, :k]).astype(np.int64)
+            dists = np.array(res.dists[:, :k])
+            # empty slots resolve to the pad row: mask BOTH columns (an id of
+            # -1 must never ship with the pad row's finite distance)
+            pad = ids >= self.graph.n
+            ids[pad] = -1
+            dists[pad] = np.inf
+            stats = SearchStats.from_result(res, router=spec.router)
+        return ids, dists, stats
 
     # --- persistence ----------------------------------------------------------
     def _payload(self) -> Dict[str, np.ndarray]:

@@ -80,6 +80,12 @@ STATUS_UNVISITED = 0
 STATUS_VISITED = 1
 STATUS_PRUNED = 2
 
+# the ``jax.named_scope`` of each phase of ``_search_batch``, in program
+# order: HLO ``op_name`` metadata only (fusion and codegen are unchanged),
+# which a profiler trace carries on every device op
+PHASE_SCOPES = ("search.init", "search.descend", "hop.select", "hop.route",
+                "hop.distance", "hop.scatter", "hop.merge", "search.finish")
+
 
 class SearchResult(NamedTuple):
     ids: jax.Array        # [B, efs] int32, N = empty
@@ -313,6 +319,11 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
     traversal trace: recall through a sparsely-tombstoned region matches
     the undeleted graph's routing behavior (FreshDiskANN-style filtered
     search).  ``None`` compiles the mask out entirely.
+
+    Each phase runs under its named scope (``PHASE_SCOPES``): the state
+    set-up, the upper-layer descent, then per hop the beam selection and
+    neighbor tile, the router's estimate and prune, the distances, the
+    status scatter and the pool merge, and the work after the loop.
     """
     metric, efs, n = cfg.metric, cfg.efs, arrays["n"]
     W, engine = cfg.beam_width, cfg.engine
@@ -347,8 +358,9 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
         if sq8_on:
             code_words = arrays["sq8_words"]
 
-    nq = (jnp.linalg.norm(queries, axis=1) if metric != "l2"
-          else jnp.ones((B,), jnp.float32))
+    with jax.named_scope("search.init"):
+        nq = (jnp.linalg.norm(queries, axis=1) if metric != "l2"
+              else jnp.ones((B,), jnp.float32))
 
     def _exact_rerank(ids, mask):
         """Stage-2: masked exact ranking distances for pool entries.  The
@@ -363,39 +375,41 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
             r = _rank_tile(queries, arrays["vectors"][idx], metric)
         return jnp.where(mask, r, jnp.inf)
 
-    if cfg.use_hierarchy:
-        entry, d_entry, calls0 = jax.vmap(
-            lambda q: _descend(arrays, q, cfg))(queries)
-    else:
-        entry = jnp.broadcast_to(arrays["entry"], (B,)).astype(jnp.int32)
-        ev = jnp.broadcast_to(arrays["vectors"][arrays["entry"]],
-                              (B, queries.shape[1]))
-        d_entry = _rank_tile(queries, ev[:, None, :], metric)[:, 0]
-        calls0 = jnp.ones((B,), jnp.int32)
+    with jax.named_scope("search.descend"):
+        if cfg.use_hierarchy:
+            entry, d_entry, calls0 = jax.vmap(
+                lambda q: _descend(arrays, q, cfg))(queries)
+        else:
+            entry = jnp.broadcast_to(arrays["entry"], (B,)).astype(jnp.int32)
+            ev = jnp.broadcast_to(arrays["vectors"][arrays["entry"]],
+                                  (B, queries.shape[1]))
+            d_entry = _rank_tile(queries, ev[:, None, :], metric)[:, 0]
+            calls0 = jnp.ones((B,), jnp.int32)
 
-    if valid is None:
-        done0 = jnp.zeros((B,), bool)
-    else:
-        # padded lanes are born done: zero hops, zero counters (the entry
-        # distance above is masked out of calls0 too)
-        done0 = ~valid
-        calls0 = jnp.where(valid, calls0, 0)
+    with jax.named_scope("search.init"):
+        if valid is None:
+            done0 = jnp.zeros((B,), bool)
+        else:
+            # padded lanes are born done: zero hops, zero counters (the entry
+            # distance above is masked out of calls0 too)
+            done0 = ~valid
+            calls0 = jnp.where(valid, calls0, 0)
 
-    pool_d = jnp.full((B, efs), jnp.inf, jnp.float32).at[:, 0].set(d_entry)
-    pool_id = jnp.full((B, efs), n, jnp.int32).at[:, 0].set(entry)
-    pool_exp = jnp.zeros((B, efs), bool)
-    pool_apx = jnp.zeros((B, efs), bool)   # slot holds a stage-1 estimate
-    status = jnp.zeros((B, n + 1), jnp.uint8).at[rows, entry].set(STATUS_VISITED)
+        pool_d = jnp.full((B, efs), jnp.inf, jnp.float32).at[:, 0].set(d_entry)
+        pool_id = jnp.full((B, efs), n, jnp.int32).at[:, 0].set(entry)
+        pool_exp = jnp.zeros((B, efs), bool)
+        pool_apx = jnp.zeros((B, efs), bool)   # slot holds a stage-1 estimate
+        status = jnp.zeros((B, n + 1), jnp.uint8).at[rows, entry].set(STATUS_VISITED)
 
-    State = (pool_d, pool_id, pool_exp, pool_apx, status, calls0,
-             jnp.zeros((B,), jnp.int32),   # est_calls
-             jnp.zeros((B,), jnp.int32),   # rerank_calls
-             jnp.zeros((B,), jnp.int32),   # sq8_calls
-             # per-router counters (registry-declared, see Router.extra_counters)
-             {name: jnp.zeros((B,), jnp.int32) for name in rt.extra_counters},
-             jnp.zeros((B,), jnp.int32),   # hops
-             done0,                        # done (padded lanes born done)
-             jnp.asarray(0, jnp.int32))    # iters
+        State = (pool_d, pool_id, pool_exp, pool_apx, status, calls0,
+                 jnp.zeros((B,), jnp.int32),   # est_calls
+                 jnp.zeros((B,), jnp.int32),   # rerank_calls
+                 jnp.zeros((B,), jnp.int32),   # sq8_calls
+                 # per-router counters (registry-declared, see Router.extra_counters)
+                 {name: jnp.zeros((B,), jnp.int32) for name in rt.extra_counters},
+                 jnp.zeros((B,), jnp.int32),   # hops
+                 done0,                        # done (padded lanes born done)
+                 jnp.asarray(0, jnp.int32))    # iters
 
     def cond(s):
         *_, done, iters = s
@@ -406,70 +420,71 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
          rrcalls, sqcalls, extras, hops, done, iters) = s
 
         # --- beam selection: best W unexpanded pool entries per query ------
-        cand = (~pool_exp) & (pool_id < n)
-        cand_d = jnp.where(cand, pool_d, jnp.inf)
-        neg_top, beam_idx = jax.lax.top_k(-cand_d, W)          # [B, W]
-        beam_d = -neg_top
-        pool_full = pool_id[:, efs - 1] < n
-        upper = jnp.where(pool_full, pool_d[:, efs - 1], jnp.inf)  # [B]
-        active = (~done) & (hops < cfg.max_hops)
-        slot_live = jnp.isfinite(beam_d) & (beam_d <= upper[:, None]) \
-            & active[:, None]                                   # [B, W]
-        # keep the per-query hop budget exact: only the first
-        # (max_hops - hops) live slots may expand this iteration
-        budget = cfg.max_hops - hops                            # [B]
-        slot_live = slot_live & (jnp.cumsum(slot_live, axis=1)
-                                 <= budget[:, None])
-        done = done | ~jnp.any(slot_live, axis=1)
+        with jax.named_scope("hop.select"):
+            cand = (~pool_exp) & (pool_id < n)
+            cand_d = jnp.where(cand, pool_d, jnp.inf)
+            neg_top, beam_idx = jax.lax.top_k(-cand_d, W)          # [B, W]
+            beam_d = -neg_top
+            pool_full = pool_id[:, efs - 1] < n
+            upper = jnp.where(pool_full, pool_d[:, efs - 1], jnp.inf)  # [B]
+            active = (~done) & (hops < cfg.max_hops)
+            slot_live = jnp.isfinite(beam_d) & (beam_d <= upper[:, None]) \
+                & active[:, None]                                   # [B, W]
+            # keep the per-query hop budget exact: only the first
+            # (max_hops - hops) live slots may expand this iteration
+            budget = cfg.max_hops - hops                            # [B]
+            slot_live = slot_live & (jnp.cumsum(slot_live, axis=1)
+                                     <= budget[:, None])
+            done = done | ~jnp.any(slot_live, axis=1)
 
-        c = jnp.where(slot_live,
-                      jnp.take_along_axis(pool_id, beam_idx, axis=1),
-                      n).astype(jnp.int32)                      # [B, W]
-        dc = jnp.take_along_axis(pool_d, beam_idx, axis=1)      # [B, W]
-        if sq8_on:
-            # stage-2 rerank at expansion: an approx entry selected for the
-            # beam gets its exact distance (and its flag cleared) before its
-            # stored distance is used as d(c, q) for the tile's estimates
-            sel_apx = jnp.take_along_axis(pool_apx, beam_idx, axis=1) \
-                & slot_live
-            dc = jnp.where(sel_apx, _exact_rerank(c, sel_apx), dc)
-            pool_d = pool_d.at[rows[:, None], beam_idx].set(dc)
-            pool_apx = pool_apx.at[rows[:, None], beam_idx].set(
-                jnp.take_along_axis(pool_apx, beam_idx, axis=1) & ~sel_apx)
-            nrr = jnp.sum(sel_apx, axis=1, dtype=jnp.int32)
-            rrcalls = rrcalls + nrr
-            dcalls = dcalls + nrr
-        pool_exp = pool_exp.at[rows[:, None], beam_idx].set(
-            jnp.take_along_axis(pool_exp, beam_idx, axis=1) | slot_live)
+            c = jnp.where(slot_live,
+                          jnp.take_along_axis(pool_id, beam_idx, axis=1),
+                          n).astype(jnp.int32)                      # [B, W]
+            dc = jnp.take_along_axis(pool_d, beam_idx, axis=1)      # [B, W]
+            if sq8_on:
+                # stage-2 rerank at expansion: an approx entry selected for the
+                # beam gets its exact distance (and its flag cleared) before its
+                # stored distance is used as d(c, q) for the tile's estimates
+                sel_apx = jnp.take_along_axis(pool_apx, beam_idx, axis=1) \
+                    & slot_live
+                dc = jnp.where(sel_apx, _exact_rerank(c, sel_apx), dc)
+                pool_d = pool_d.at[rows[:, None], beam_idx].set(dc)
+                pool_apx = pool_apx.at[rows[:, None], beam_idx].set(
+                    jnp.take_along_axis(pool_apx, beam_idx, axis=1) & ~sel_apx)
+                nrr = jnp.sum(sel_apx, axis=1, dtype=jnp.int32)
+                rrcalls = rrcalls + nrr
+                dcalls = dcalls + nrr
+            pool_exp = pool_exp.at[rows[:, None], beam_idx].set(
+                jnp.take_along_axis(pool_exp, beam_idx, axis=1) | slot_live)
 
-        # --- dense [B, W*M] neighbor tile ----------------------------------
-        nbrs = arrays["neighbors"][c].reshape(B, L)             # [B, L]
-        # stored edge distances may be bf16 (§Perf HC3); estimate math in f32
-        ed = arrays["edge_eu"][c].astype(jnp.float32).reshape(B, L)
-        st = jnp.take_along_axis(status, nbrs, axis=1)          # [B, L]
-        in_range = nbrs < n
-        lane_live = jnp.broadcast_to(slot_live[:, :, None],
-                                     (B, W, M)).reshape(B, L)
-        valid = in_range & (st != STATUS_VISITED) & lane_live
-        if not rt.revisit_pruned:
-            # no error correction (crouting_o): pruned lanes stay skipped
-            valid = valid & (st != STATUS_PRUNED)
-        if W > 1:
-            first, dd_order, dd_keys = _first_occurrence(nbrs, valid, n)
-        else:
-            first = valid
+            # --- dense [B, W*M] neighbor tile ----------------------------------
+            nbrs = arrays["neighbors"][c].reshape(B, L)             # [B, L]
+            # stored edge distances may be bf16 (§Perf HC3); estimate math in f32
+            ed = arrays["edge_eu"][c].astype(jnp.float32).reshape(B, L)
+            st = jnp.take_along_axis(status, nbrs, axis=1)          # [B, L]
+            in_range = nbrs < n
+            lane_live = jnp.broadcast_to(slot_live[:, :, None],
+                                         (B, W, M)).reshape(B, L)
+            valid = in_range & (st != STATUS_VISITED) & lane_live
+            if not rt.revisit_pruned:
+                # no error correction (crouting_o): pruned lanes stay skipped
+                valid = valid & (st != STATUS_PRUNED)
+            if W > 1:
+                first, dd_order, dd_keys = _first_occurrence(nbrs, valid, n)
+            else:
+                first = valid
 
-        norms_c = arrays["norms"][c]                            # [B, W]
-        dcq_eu = _rank_to_eu(dc, nq[:, None], norms_c, metric)  # [B, W]
-        dcq_l = jnp.broadcast_to(dcq_eu[:, :, None], (B, W, M)).reshape(B, L)
-        nx = arrays["norms"][nbrs]                              # [B, L]
+            norms_c = arrays["norms"][c]                            # [B, W]
+            dcq_eu = _rank_to_eu(dc, nq[:, None], norms_c, metric)  # [B, W]
+            dcq_l = jnp.broadcast_to(dcq_eu[:, :, None], (B, W, M)).reshape(B, L)
+            nx = arrays["norms"][nbrs]                              # [B, L]
 
-        if metric == "l2":
-            bound2 = jnp.broadcast_to(upper[:, None], (B, L))
-        else:
-            # est_rank >= upper  <=>  est2 >= inverse rank->eu^2 per lane
-            bound2 = 2.0 * upper[:, None] + nx * nx \
-                + (nq * nq)[:, None] - 2.0
+            if metric == "l2":
+                bound2 = jnp.broadcast_to(upper[:, None], (B, L))
+            else:
+                # est_rank >= upper  <=>  est2 >= inverse rank->eu^2 per lane
+                bound2 = 2.0 * upper[:, None] + nx * nx \
+                    + (nq * nq)[:, None] - 2.0
 
         # --- router: estimate + prune (no neighbor-vector fetch here).
         # Edge-angle routers (Router.kernel_estimate) may have the decision
@@ -482,193 +497,199 @@ def _search_batch(arrays, queries, cos_theta, cfg: SearchSpec, valid=None,
         # (W>1, error-correcting router) must know prune BEFORE the fetch
         # set exists, so there the hook decides and the fused kernel's
         # eligible set is empty (its DMA skip still comes from eval_mask). -
-        prunes = rt.prunes
-        ct_eff = rt.cos_theta_eff(cos_theta)
-        rescue = W > 1 and prunes and rt.revisit_pruned and not rt.permanent
-        # with sq8 the fused fp32 kernel never runs, so the prune decision
-        # is made outside it (jnp / crouting_prune — the same f32 math)
-        kernel_prunes = engine == "pallas" and rt.kernel_estimate \
-            and not rescue and not sq8_on
-        if prunes:
-            try_prune = first & (st == STATUS_UNVISITED) & pool_full[:, None]
-            if W > 1 and cfg.beam_prune == "best":
-                # top_k orders slots by distance, so slot 0 = the node
-                # sequential search would be expanding right now; only its
-                # lanes run the estimate test (see SearchSpec.beam_prune)
-                try_prune = try_prune & (jnp.arange(L) < M)[None, :]
-            if rt.counts_est:
-                ecalls = ecalls + jnp.sum(try_prune, axis=1, dtype=jnp.int32)
-        else:
-            try_prune = jnp.zeros_like(first)
+        with jax.named_scope("hop.route"):
+            prunes = rt.prunes
+            ct_eff = rt.cos_theta_eff(cos_theta)
+            rescue = W > 1 and prunes and rt.revisit_pruned and not rt.permanent
+            # with sq8 the fused fp32 kernel never runs, so the prune decision
+            # is made outside it (jnp / crouting_prune — the same f32 math)
+            kernel_prunes = engine == "pallas" and rt.kernel_estimate \
+                and not rescue and not sq8_on
+            if prunes:
+                try_prune = first & (st == STATUS_UNVISITED) & pool_full[:, None]
+                if W > 1 and cfg.beam_prune == "best":
+                    # top_k orders slots by distance, so slot 0 = the node
+                    # sequential search would be expanding right now; only its
+                    # lanes run the estimate test (see SearchSpec.beam_prune)
+                    try_prune = try_prune & (jnp.arange(L) < M)[None, :]
+                if rt.counts_est:
+                    ecalls = ecalls + jnp.sum(try_prune, axis=1, dtype=jnp.int32)
+            else:
+                try_prune = jnp.zeros_like(first)
 
-        if not prunes or kernel_prunes:
-            prune = jnp.zeros_like(first)
-        elif engine == "pallas_unfused" and rt.kernel_estimate:
-            _, prune8 = ops.crouting_prune(ed, dcq_l, bound2, try_prune,
-                                           ct_eff)
-            prune = prune8 != 0
-        else:
-            ctx = RouterContext(
-                arrays=arrays, queries=queries, nq=nq, c=c, dc=dc, nbrs=nbrs,
-                ed=ed, dcq=dcq_l, nx=nx, try_prune=try_prune, upper=upper,
-                cos_theta=cos_theta, metric=metric, n=n, beam_width=W,
-                max_degree=M)
-            est_rank, extra_inc = rt.estimate_rank(ctx)
-            prune = try_prune & (est_rank >= upper[:, None])
-            # repolint: ignore[trace-safety] extra_inc is a host dict of
-            # counter names (Router.extra_counters), not a tracer — its
-            # truthiness is concrete during tracing
-            extras = {key: extras[key] + extra_inc.get(key, 0)
-                      for key in extras} if extra_inc else extras
+            if not prunes or kernel_prunes:
+                prune = jnp.zeros_like(first)
+            elif engine == "pallas_unfused" and rt.kernel_estimate:
+                _, prune8 = ops.crouting_prune(ed, dcq_l, bound2, try_prune,
+                                               ct_eff)
+                prune = prune8 != 0
+            else:
+                ctx = RouterContext(
+                    arrays=arrays, queries=queries, nq=nq, c=c, dc=dc, nbrs=nbrs,
+                    ed=ed, dcq=dcq_l, nx=nx, try_prune=try_prune, upper=upper,
+                    cos_theta=cos_theta, metric=metric, n=n, beam_width=W,
+                    max_degree=M)
+                est_rank, extra_inc = rt.estimate_rank(ctx)
+                prune = try_prune & (est_rank >= upper[:, None])
+                # repolint: ignore[trace-safety] extra_inc is a host dict of
+                # counter names (Router.extra_counters), not a tracer — its
+                # truthiness is concrete during tracing
+                extras = {key: extras[key] + extra_inc.get(key, 0)
+                          for key in extras} if extra_inc else extras
 
-        if rescue:
-            # Within-tile error correction (paper Alg. 2): sequentially, the
-            # second encounter of a just-pruned node recomputes it exactly
-            # (status PRUNED exempts it from re-estimation).  Collapsed into
-            # the tile: a second valid lane of a pruned id computes, and the
-            # id is then VISITED, not PRUNED.  Without this, beam dedup
-            # silently disables error correction and recall drops.
-            rescued, prune_kept = _rescue_pruned_duplicates(dd_order, dd_keys,
-                                                            prune)
-            compute = (first & ~prune) | rescued
-            prune = prune_kept    # rescued ids end VISITED, not PRUNED
-        else:
-            compute = first & ~prune
+            if rescue:
+                # Within-tile error correction (paper Alg. 2): sequentially, the
+                # second encounter of a just-pruned node recomputes it exactly
+                # (status PRUNED exempts it from re-estimation).  Collapsed into
+                # the tile: a second valid lane of a pruned id computes, and the
+                # id is then VISITED, not PRUNED.  Without this, beam dedup
+                # silently disables error correction and recall drops.
+                rescued, prune_kept = _rescue_pruned_duplicates(dd_order, dd_keys,
+                                                                prune)
+                compute = (first & ~prune) | rescued
+                prune = prune_kept    # rescued ids end VISITED, not PRUNED
+            else:
+                compute = first & ~prune
 
         # --- distances: stage-1 quantized estimate (sq8) or exact fp32 ------
-        if sq8_on:
-            # stage 1: uint8 code-row gather + dequantized accumulate +
-            # conservative lower bound for EVERY surviving lane — no fp32
-            # row DMA on this path (that is stage 2's job, in _exact_rerank)
-            if use_pallas:
-                ad2, lb2 = ops.sq8_estimate(
-                    nbrs, queries, compute, code_words,
-                    arrays["sq8_lo"], arrays["sq8_scale"], arrays["sq8_eps"])
+        with jax.named_scope("hop.distance"):
+            if sq8_on:
+                # stage 1: uint8 code-row gather + dequantized accumulate +
+                # conservative lower bound for EVERY surviving lane — no fp32
+                # row DMA on this path (that is stage 2's job, in _exact_rerank)
+                if use_pallas:
+                    ad2, lb2 = ops.sq8_estimate(
+                        nbrs, queries, compute, code_words,
+                        arrays["sq8_lo"], arrays["sq8_scale"], arrays["sq8_eps"])
+                else:
+                    from repro.quant.sq8 import sq8_dequantize_rows, sq8_estimate
+                    xhat = sq8_dequantize_rows(
+                        arrays["sq8_codes"][jnp.where(compute, nbrs, n)],
+                        arrays["sq8_lo"], arrays["sq8_scale"])
+                    ad2, lb2 = sq8_estimate(queries, xhat, arrays["sq8_eps"])
+                    ad2 = jnp.where(compute, ad2, jnp.inf)
+                    lb2 = jnp.where(compute, lb2, jnp.inf)
+                ad_rank = _eu2_to_rank(ad2, nq[:, None], nx, metric)
+                lb_rank = _eu2_to_rank(lb2, nq[:, None], nx, metric)
+                # a lane whose true distance provably cannot beat the pool bound
+                # is discarded without its fp32 row; PRUNED (not VISITED) so a
+                # later encounter may re-estimate it against a tighter bound
+                sq8_skip = compute & pool_full[:, None] \
+                    & (lb_rank >= upper[:, None])
+                insert = compute & ~sq8_skip
+                sqcalls = sqcalls + jnp.sum(compute, axis=1, dtype=jnp.int32)
+                new_d = jnp.where(insert, ad_rank, jnp.inf)
             else:
-                from repro.quant.sq8 import sq8_dequantize_rows, sq8_estimate
-                xhat = sq8_dequantize_rows(
-                    arrays["sq8_codes"][jnp.where(compute, nbrs, n)],
-                    arrays["sq8_lo"], arrays["sq8_scale"])
-                ad2, lb2 = sq8_estimate(queries, xhat, arrays["sq8_eps"])
-                ad2 = jnp.where(compute, ad2, jnp.inf)
-                lb2 = jnp.where(compute, lb2, jnp.inf)
-            ad_rank = _eu2_to_rank(ad2, nq[:, None], nx, metric)
-            lb_rank = _eu2_to_rank(lb2, nq[:, None], nx, metric)
-            # a lane whose true distance provably cannot beat the pool bound
-            # is discarded without its fp32 row; PRUNED (not VISITED) so a
-            # later encounter may re-estimate it against a tighter bound
-            sq8_skip = compute & pool_full[:, None] \
-                & (lb_rank >= upper[:, None])
-            insert = compute & ~sq8_skip
-            sqcalls = sqcalls + jnp.sum(compute, axis=1, dtype=jnp.int32)
-            new_d = jnp.where(insert, ad_rank, jnp.inf)
-        else:
-            # exact fp32 distances (masked; non-compute lanes skip the HBM
-            # row fetch on real TPU)
-            if engine == "pallas":
-                d2eu, prune8 = ops.fused_expand(
-                    nbrs, queries, ed, dcq_l, bound2, ct_eff,
-                    vec_rows, eval_mask=compute,
-                    prune_eligible=try_prune if kernel_prunes
-                    else jnp.zeros_like(try_prune))
-                if kernel_prunes:
-                    # the kernel both made the prune decision and skipped
-                    # those lanes' DMAs (eval ∩ eligible lanes fetch only if
-                    # unpruned)
-                    prune = prune8 != 0
-                    compute = compute & ~prune
-                exact = _eu2_to_rank(d2eu, nq[:, None], nx, metric)
-            elif engine == "pallas_unfused":
-                d2eu = ops.gather_distance_pruned(
-                    jnp.where(compute, nbrs, n), (~compute).astype(jnp.int8),
-                    queries, vec_rows)
-                exact = _eu2_to_rank(d2eu, nq[:, None], nx, metric)
-            else:
-                gathered = arrays["vectors"][jnp.where(compute, nbrs, n)]
-                exact = _rank_tile(queries, gathered, metric)
-            insert = compute
-            new_d = jnp.where(compute, exact, jnp.inf)
-            dcalls = dcalls + jnp.sum(compute, axis=1, dtype=jnp.int32)
+                # exact fp32 distances (masked; non-compute lanes skip the HBM
+                # row fetch on real TPU)
+                if engine == "pallas":
+                    d2eu, prune8 = ops.fused_expand(
+                        nbrs, queries, ed, dcq_l, bound2, ct_eff,
+                        vec_rows, eval_mask=compute,
+                        prune_eligible=try_prune if kernel_prunes
+                        else jnp.zeros_like(try_prune))
+                    if kernel_prunes:
+                        # the kernel both made the prune decision and skipped
+                        # those lanes' DMAs (eval ∩ eligible lanes fetch only if
+                        # unpruned)
+                        prune = prune8 != 0
+                        compute = compute & ~prune
+                    exact = _eu2_to_rank(d2eu, nq[:, None], nx, metric)
+                elif engine == "pallas_unfused":
+                    d2eu = ops.gather_distance_pruned(
+                        jnp.where(compute, nbrs, n), (~compute).astype(jnp.int8),
+                        queries, vec_rows)
+                    exact = _eu2_to_rank(d2eu, nq[:, None], nx, metric)
+                else:
+                    gathered = arrays["vectors"][jnp.where(compute, nbrs, n)]
+                    exact = _rank_tile(queries, gathered, metric)
+                insert = compute
+                new_d = jnp.where(compute, exact, jnp.inf)
+                dcalls = dcalls + jnp.sum(compute, axis=1, dtype=jnp.int32)
 
         # --- status scatter: only lanes whose status changes write; all
         # other lanes are redirected to the pad column (same-value writes,
         # so the scatter stays deterministic) -------------------------------
-        change = compute | prune
-        if rt.permanent:
-            # exact/trusted bound => discard is permanent (mark visited)
-            new_st = jnp.full_like(st, STATUS_VISITED)
-        else:
-            new_st = jnp.where(insert, STATUS_VISITED, STATUS_PRUNED
-                               ).astype(jnp.uint8)
-        pad_val = status[:, n][:, None]
-        status = status.at[rows[:, None], jnp.where(change, nbrs, n)].set(
-            jnp.where(change, new_st, pad_val))
+        with jax.named_scope("hop.scatter"):
+            change = compute | prune
+            if rt.permanent:
+                # exact/trusted bound => discard is permanent (mark visited)
+                new_st = jnp.full_like(st, STATUS_VISITED)
+            else:
+                new_st = jnp.where(insert, STATUS_VISITED, STATUS_PRUNED
+                                   ).astype(jnp.uint8)
+            pad_val = status[:, n][:, None]
+            status = status.at[rows[:, None], jnp.where(change, nbrs, n)].set(
+                jnp.where(change, new_st, pad_val))
 
         # --- pool merge (merge-then-truncate == evolving-bound insertion) ---
-        new_id = jnp.where(insert, nbrs, n).astype(jnp.int32)
-        new_apx = insert if sq8_on else jnp.zeros_like(insert)
-        if use_pallas:
-            # approx + expanded flags ride the bitonic network in the id
-            # low bits
-            enc_pool = pool_id * 4 + pool_apx.astype(jnp.int32) * 2 \
-                + pool_exp.astype(jnp.int32)
-            enc_new = new_id * 4 + new_apx.astype(jnp.int32) * 2
-            pool_d, enc = ops.pool_merge(pool_d, enc_pool, new_d, enc_new)
-            pool_id = enc // 4
-            pool_apx = (enc & 2) == 2
-            pool_exp = (enc & 1) == 1
-        else:
-            md = jnp.concatenate([pool_d, new_d], axis=1)
-            mi = jnp.concatenate([pool_id, new_id], axis=1)
-            me = jnp.concatenate([pool_exp, jnp.zeros_like(insert)], axis=1)
-            ma = jnp.concatenate([pool_apx, new_apx], axis=1)
-            # lexicographic (dist, id) — the SAME tie-break as the pallas
-            # pool_merge network, so the engines agree even on exact ties
-            order = jnp.lexsort((mi, md), axis=1)[:, :efs]
-            pool_d = jnp.take_along_axis(md, order, axis=1)
-            pool_id = jnp.take_along_axis(mi, order, axis=1)
-            pool_exp = jnp.take_along_axis(me, order, axis=1)
-            pool_apx = jnp.take_along_axis(ma, order, axis=1)
+        with jax.named_scope("hop.merge"):
+            new_id = jnp.where(insert, nbrs, n).astype(jnp.int32)
+            new_apx = insert if sq8_on else jnp.zeros_like(insert)
+            if use_pallas:
+                # approx + expanded flags ride the bitonic network in the id
+                # low bits
+                enc_pool = pool_id * 4 + pool_apx.astype(jnp.int32) * 2 \
+                    + pool_exp.astype(jnp.int32)
+                enc_new = new_id * 4 + new_apx.astype(jnp.int32) * 2
+                pool_d, enc = ops.pool_merge(pool_d, enc_pool, new_d, enc_new)
+                pool_id = enc // 4
+                pool_apx = (enc & 2) == 2
+                pool_exp = (enc & 1) == 1
+            else:
+                md = jnp.concatenate([pool_d, new_d], axis=1)
+                mi = jnp.concatenate([pool_id, new_id], axis=1)
+                me = jnp.concatenate([pool_exp, jnp.zeros_like(insert)], axis=1)
+                ma = jnp.concatenate([pool_apx, new_apx], axis=1)
+                # lexicographic (dist, id) — the SAME tie-break as the pallas
+                # pool_merge network, so the engines agree even on exact ties
+                order = jnp.lexsort((mi, md), axis=1)[:, :efs]
+                pool_d = jnp.take_along_axis(md, order, axis=1)
+                pool_id = jnp.take_along_axis(mi, order, axis=1)
+                pool_exp = jnp.take_along_axis(me, order, axis=1)
+                pool_apx = jnp.take_along_axis(ma, order, axis=1)
 
-        hops = hops + jnp.sum(slot_live, axis=1, dtype=jnp.int32)
+        with jax.named_scope("hop.select"):
+            hops = hops + jnp.sum(slot_live, axis=1, dtype=jnp.int32)
         return (pool_d, pool_id, pool_exp, pool_apx, status, dcalls, ecalls,
                 rrcalls, sqcalls, extras, hops, done, iters + 1)
 
     (pool_d, pool_id, pool_exp, pool_apx, status, dcalls, ecalls, rrcalls,
      sqcalls, extras, hops, done, iters) = jax.lax.while_loop(cond, body,
                                                               State)
-    if tombstone is not None:
-        # emission-time masking: dead entries routed normally through the
-        # loop above; here they collapse to the pad sentinel so neither the
-        # sq8 final rerank nor the caller ever sees them
-        dead = tombstone[pool_id]          # pool_id in [0..n]; row n is False
-        pool_d = jnp.where(dead, jnp.inf, pool_d)
-        pool_id = jnp.where(dead, n, pool_id)
-    if sq8_on:
-        # stage-2 final rerank: every approx survivor still in the pool gets
-        # its exact distance before results can be returned; entries
-        # displaced earlier never paid their fp32 fetch
-        mask = pool_apx & (pool_id < n)
-        pool_d = jnp.where(mask, _exact_rerank(pool_id, mask), pool_d)
-        nrr = jnp.sum(mask, axis=1, dtype=jnp.int32)
-        rrcalls = rrcalls + nrr
-        dcalls = dcalls + nrr
-        order = jnp.lexsort((pool_id, pool_d), axis=1)
-        pool_d = jnp.take_along_axis(pool_d, order, axis=1)
-        pool_id = jnp.take_along_axis(pool_id, order, axis=1)
-    elif tombstone is not None:
-        # the sq8 branch above already re-sorted; the exact path must push
-        # the newly-masked dead slots behind the survivors itself
-        order = jnp.lexsort((pool_id, pool_d), axis=1)
-        pool_d = jnp.take_along_axis(pool_d, order, axis=1)
-        pool_id = jnp.take_along_axis(pool_id, order, axis=1)
-    if valid is not None:
-        # belt and braces: padded lanes never ran, but the counters must be
-        # provably zero whatever path produced them (they feed shard psums)
-        dcalls, ecalls, rrcalls, sqcalls, hops = (
-            jnp.where(valid, a, 0)
-            for a in (dcalls, ecalls, rrcalls, sqcalls, hops))
-        extras = {k: jnp.where(valid, v, 0) for k, v in extras.items()}
+    with jax.named_scope("search.finish"):
+        if tombstone is not None:
+            # emission-time masking: dead entries routed normally through the
+            # loop above; here they collapse to the pad sentinel so neither the
+            # sq8 final rerank nor the caller ever sees them
+            dead = tombstone[pool_id]          # pool_id in [0..n]; row n is False
+            pool_d = jnp.where(dead, jnp.inf, pool_d)
+            pool_id = jnp.where(dead, n, pool_id)
+        if sq8_on:
+            # stage-2 final rerank: every approx survivor still in the pool gets
+            # its exact distance before results can be returned; entries
+            # displaced earlier never paid their fp32 fetch
+            mask = pool_apx & (pool_id < n)
+            pool_d = jnp.where(mask, _exact_rerank(pool_id, mask), pool_d)
+            nrr = jnp.sum(mask, axis=1, dtype=jnp.int32)
+            rrcalls = rrcalls + nrr
+            dcalls = dcalls + nrr
+            order = jnp.lexsort((pool_id, pool_d), axis=1)
+            pool_d = jnp.take_along_axis(pool_d, order, axis=1)
+            pool_id = jnp.take_along_axis(pool_id, order, axis=1)
+        elif tombstone is not None:
+            # the sq8 branch above already re-sorted; the exact path must push
+            # the newly-masked dead slots behind the survivors itself
+            order = jnp.lexsort((pool_id, pool_d), axis=1)
+            pool_d = jnp.take_along_axis(pool_d, order, axis=1)
+            pool_id = jnp.take_along_axis(pool_id, order, axis=1)
+        if valid is not None:
+            # belt and braces: padded lanes never ran, but the counters must be
+            # provably zero whatever path produced them (they feed shard psums)
+            dcalls, ecalls, rrcalls, sqcalls, hops = (
+                jnp.where(valid, a, 0)
+                for a in (dcalls, ecalls, rrcalls, sqcalls, hops))
+            extras = {k: jnp.where(valid, v, 0) for k, v in extras.items()}
     return SearchResult(ids=pool_id, dists=pool_d, dist_calls=dcalls,
                         est_calls=ecalls, hops=hops, iters=iters,
                         rerank_calls=rrcalls, sq8_calls=sqcalls, extra=extras)

@@ -11,8 +11,7 @@ Straggler mitigation: the per-shard search runs a *fixed hop budget*
 quality degrades gracefully instead of latency (tested in
 tests/test_sharded_index.py).
 
-`serve_step` is the function the multi-pod dry-run lowers for the ANNS
-configs; it is pure pjit (shard_map inside) and scales to any mesh by
+`serve_step` is the sharded search step; it is pure pjit (shard_map inside) and scales to any mesh by
 flattening all mesh axes into the shard axis.
 """
 from __future__ import annotations

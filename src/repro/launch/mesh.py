@@ -1,8 +1,8 @@
 """Production mesh construction (DESIGN.md §6).
 
 A FUNCTION, not a module-level constant: importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS before any jax init; smoke tests
-see the real single device).
+jax device state (a caller may set XLA_FLAGS before any jax init; smoke
+tests see the real single device).
 """
 from __future__ import annotations
 

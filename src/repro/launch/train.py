@@ -1,8 +1,7 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-Runs a REDUCED config end-to-end on local devices (the full configs only
-lower via dryrun.py on this CPU container; on a real TPU slice pass
---full to use the assigned config with the production mesh).
+Runs a REDUCED config end-to-end on local devices (on a real TPU slice
+pass --full to use the assigned config with the production mesh).
 """
 from __future__ import annotations
 

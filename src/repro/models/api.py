@@ -1,7 +1,7 @@
 """Cell builder: (architecture x input-shape) -> lowerable step + specs.
 
-The dry-run (launch/dryrun.py) and roofline analysis consume Cells; smoke
-tests consume build_smoke().  Everything here is ShapeDtypeStruct-based — no
+Cells are lowerable shape specs (nothing in the serve path consumes
+them); smoke tests consume build_smoke().  Everything here is ShapeDtypeStruct-based — no
 parameter allocation for the full configs (DESIGN.md deliverable (f))."""
 from __future__ import annotations
 

@@ -6,7 +6,7 @@ the layer axis and the forward pass is one `lax.scan` (+ per-step remat) so
 the HLO stays small enough to compile 36-layer × 512-device programs on the
 CPU dry-run host.
 
-Steps exposed (launch/dryrun.py lowers these):
+Steps exposed:
   train_step    causal-LM loss + AdamW update (train_* shapes)
   prefill_step  full-sequence forward that also emits the KV cache (prefill_*)
   serve_step    one-token decode against a KV cache (decode_* / long_*)

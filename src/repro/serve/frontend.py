@@ -32,10 +32,17 @@ Threading: ``flush()`` is synchronous and deterministic (tests, benchmarks
 drive it directly).  ``start()`` spawns a daemon worker that flushes
 whenever requests are pending — the launcher's "serve forever" mode.  Both
 may run concurrently; the queue and dispatch path are lock-protected.
+
+Tracing: each engine call is a host span ``frontend.dispatch``
+(``core/spans.py``) carrying its dispatch id, rows and bucket;
+``frontend.batch``, the index's own spans and ``frontend.resolve`` nest
+inside it with the same id, and the worker's idle wait is
+``frontend.wait``.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import deque
@@ -44,6 +51,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.spec import SearchSpec
 from repro.fault import failpoints as fault
 from repro.serve.backends import make_session
@@ -119,6 +127,7 @@ class ServeFrontend:
         self._stopped = False                   # stop() called, no start() yet
         self.worker_error: Optional[BaseException] = None
         self.autotune = None        # AutotuneDriver.attach registers itself
+        self._dispatch_ids = itertools.count(1)  # the trace's dispatch ids
         self._base = self._session(spec)
         if warmup:
             self.warmup()
@@ -341,36 +350,41 @@ class ServeFrontend:
     def _dispatch(self, sess: _Session, batch: List[_Request], rows: int,
                   cos_theta: Optional[float]):
         bucket = bucket_for(rows, self.buckets)
-        q = (batch[0].queries if len(batch) == 1
-             else np.concatenate([r.queries for r in batch], axis=0))
-        qp, _ = pad_to_bucket(q, bucket)
-        k_d = max(r.k for r in batch)
-        c0 = sess.engine.compile_count()
-        t0 = time.perf_counter()
-        try:
-            fault.hit("serve.dispatch")
-            ids, dists, stats = sess.engine.search_padded(
-                qp, rows, k_d, cos_theta)
-        except Exception as e:                     # noqa: BLE001
-            # the failure belongs to THIS batch's futures only: callers see
-            # it via result(), and the flush loop keeps dispatching the
-            # other groups/sessions (an admitted future always resolves)
-            self.telemetry.observe_dispatch_failure(len(batch))
-            for r in batch:
-                r.future.set_exception(e)
-            return
-        t1 = time.perf_counter()
-        self.telemetry.observe_dispatch(
-            bucket, rows, t1 - t0, sess.engine.compile_count() - c0, stats)
-        lo = 0
-        for r in batch:
-            hi = lo + r.n
-            r_stats = sess.engine.stats_for_rows(stats, lo, hi)
-            r.future.set_result(
-                (ids[lo:hi, :r.k], dists[lo:hi, :r.k], r_stats))
-            self.telemetry.observe_request_done(
-                t1 - r.t_submit, t0 - r.t_submit)
-            lo = hi
+        with spans.dispatch(next(self._dispatch_ids), rows=rows, bucket=bucket):
+            with spans.span("frontend.batch"):
+                q = (batch[0].queries if len(batch) == 1
+                     else np.concatenate([r.queries for r in batch], axis=0))
+                qp, _ = pad_to_bucket(q, bucket)
+            k_d = max(r.k for r in batch)
+            c0 = sess.engine.compile_count()
+            t0 = time.perf_counter()
+            try:
+                fault.hit("serve.dispatch")
+                ids, dists, stats = sess.engine.search_padded(
+                    qp, rows, k_d, cos_theta)
+            except Exception as e:                     # noqa: BLE001
+                # the failure belongs to THIS batch's futures only: callers
+                # see it via result(), and the flush loop keeps dispatching
+                # the other groups/sessions (an admitted future always
+                # resolves)
+                self.telemetry.observe_dispatch_failure(len(batch))
+                for r in batch:
+                    r.future.set_exception(e)
+                return
+            t1 = time.perf_counter()
+            with spans.span("frontend.resolve"):
+                self.telemetry.observe_dispatch(
+                    bucket, rows, t1 - t0, sess.engine.compile_count() - c0,
+                    stats)
+                lo = 0
+                for r in batch:
+                    hi = lo + r.n
+                    r_stats = sess.engine.stats_for_rows(stats, lo, hi)
+                    r.future.set_result(
+                        (ids[lo:hi, :r.k], dists[lo:hi, :r.k], r_stats))
+                    self.telemetry.observe_request_done(
+                        t1 - r.t_submit, t0 - r.t_submit)
+                    lo = hi
 
     # --- health -----------------------------------------------------------
     def health(self) -> dict:
@@ -416,7 +430,8 @@ class ServeFrontend:
 
         def loop():
             while not self._stop.is_set():
-                self._wake.wait(timeout=poll_s)
+                with spans.span("frontend.wait"):
+                    self._wake.wait(timeout=poll_s)
                 self._wake.clear()
                 try:
                     fault.hit("serve.worker")

@@ -87,6 +87,9 @@ class ServeTelemetry:
         self.queue_wait_s: Deque[float] = _window()   # guarded by: self._obs_lock
         self.submitted = 0
         self.served = 0
+        # lifetime sum of queue waits, beside ``served``: the whole run's
+        # mean wait, where ``queue_wait_s`` holds the last WINDOW only
+        self.queue_wait_s_total = 0.0   # guarded by: self._obs_lock
         self.rejected = 0           # oversized / backpressure, at submit
         self.expired = 0            # deadline passed before dispatch
         self.failed = 0             # requests resolved with an exception
@@ -143,6 +146,7 @@ class ServeTelemetry:
         seconds) — the windowed-QPS regression tests inject exact times."""
         with self._obs_lock:
             self.served += 1
+            self.queue_wait_s_total += wait_s
             self.request_lat_s.append(total_s)
             self.queue_wait_s.append(wait_s)
             self._done_t.append(time.perf_counter() if now is None else now)
@@ -226,12 +230,14 @@ class ServeTelemetry:
     def summary(self) -> Dict[str, object]:
         """JSON-ready digest; ``search`` is ``SearchStats.summary()`` over
         the merged trace — the engine counters fold into the same record the
-        benchmarks persist."""
+        benchmarks persist.  ``latency``/``queue_wait`` percentiles cover the
+        last WINDOW requests; ``queue_wait_mean_ms`` every request served."""
         merged = self.merged_stats()
         qps = self.qps()
         with self._obs_lock:
             lat = tuple(self.request_lat_s)
             wait = tuple(self.queue_wait_s)
+            served, wait_total = self.served, self.queue_wait_s_total
         out: Dict[str, object] = {
             "requests": {"submitted": self.submitted, "served": self.served,
                          "rejected": self.rejected, "expired": self.expired,
@@ -240,6 +246,8 @@ class ServeTelemetry:
             "worker_errors": self.worker_errors,
             "latency": _pcts(lat),
             "queue_wait": _pcts(wait),
+            "queue_wait_mean_ms": (round(1e3 * wait_total / served, 3)
+                                   if served else None),
             "qps": round(qps, 1) if qps else None,
             "compiles_total": sum(b.compiles for b in self.buckets.values()),
             "recompiles_after_warmup": self.recompiles_after_warmup,
