@@ -25,8 +25,10 @@ the ops wrapper for standalone use.
 This is the kernel-level realization of "CRouting skips the distance call":
 on TPU the savings are the skipped random HBM reads (DESIGN.md §3).  The
 estimate and prune decision run per lane on the scalar core, from SMEM, and
-gate that lane's row DMA (``gather_distance.gather_lanes``); the prune mask
-is written straight to an SMEM output block.
+gate that lane's row DMA; the prune mask is written straight to an SMEM
+output block.  One pass decides all bb x L lanes of a grid step and starts
+every surviving lane's DMA, the step waits for them once, and only the
+started lanes are consumed (``gather_distance.gather_lanes``).
 
 Grid: (B/bb,).  cos(theta*) arrives as a (1, 1) SMEM scalar; the per-lane
 scalars and the prune mask are (bb, L) SMEM blocks; the query rows and the
@@ -42,34 +44,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather_distance import gather_lanes, lane_sum, put_lane
+from repro.kernels.gather_distance import (gather_lanes, gather_scratch,
+                                           lane_sum, put_lane)
 
 
 def _expand_kernel(ct_ref, nbr_ref, ed_ref, dcq_ref, bound2_ref, eval_ref,
-                   elig_ref, q_ref, table_ref, dist_ref, mask_ref, buf, flags,
-                   sem, *, bb: int, n_lanes: int, k: int, d: int):
+                   elig_ref, q_ref, table_ref, dist_ref, mask_ref, buf,
+                   started, sem, *, bb: int, n_lanes: int, k: int, d: int):
     ct = ct_ref[0, 0]
     dist_ref[...] = jnp.full(dist_ref.shape, jnp.inf, dist_ref.dtype)
 
-    def per_row(r, carry):
-        q = q_ref[pl.ds(r * k, k), :]
+    def decide(r, m):
+        ed, dcq = ed_ref[r, m], dcq_ref[r, m]
+        est2 = jnp.maximum(ed * ed + dcq * dcq - 2.0 * ed * dcq * ct, 0.0)
+        prune = (elig_ref[r, m] != 0) & (est2 >= bound2_ref[r, m])
+        mask_ref[r, m] = prune.astype(jnp.int32)
+        return (eval_ref[r, m] != 0) & ~prune
 
-        def decide(m):
-            ed, dcq = ed_ref[r, m], dcq_ref[r, m]
-            est2 = jnp.maximum(ed * ed + dcq * dcq - 2.0 * ed * dcq * ct, 0.0)
-            prune = (elig_ref[r, m] != 0) & (est2 >= bound2_ref[r, m])
-            mask_ref[r, m] = prune.astype(jnp.int32)
-            return (eval_ref[r, m] != 0) & ~prune
+    def consume(r, m, row):
+        diff = q_ref[pl.ds(r * k, k), :] - row
+        put_lane(dist_ref, r, m, lane_sum(diff * diff, d))
 
-        def consume(m, row):
-            diff = q - row
-            put_lane(dist_ref, r, m, lane_sum(diff * diff, d))
-
-        gather_lanes(r, n_lanes, k, decide, nbr_ref, table_ref, buf, flags,
-                     sem, consume)
-        return carry
-
-    jax.lax.fori_loop(0, bb, per_row, 0)
+    gather_lanes(bb, n_lanes, k, decide, nbr_ref, table_ref, buf, started,
+                 sem, consume)
 
 
 @functools.partial(jax.jit, static_argnames=("d", "bb", "interpret"))
@@ -107,8 +104,6 @@ def fused_expand_pallas(nbrs, queries, ed, dcq, bound2, cos_theta,
         out_specs=[lanes(), lanes(smem)],
         out_shape=[jax.ShapeDtypeStruct((B, L), jnp.float32),
                    jax.ShapeDtypeStruct((B, L), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((L * k, 128), jnp.float32),
-                        pltpu.SMEM((L,), jnp.int32),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=gather_scratch(bb, L, k, jnp.float32),
         interpret=interpret,
     )(ct, nbrs, ed, dcq, bound2, eval_mask, prune_eligible, queries, table)

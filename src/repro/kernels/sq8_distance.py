@@ -36,11 +36,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather_distance import gather_lanes, lane_sum, put_lane
+from repro.kernels.gather_distance import (gather_lanes, gather_scratch,
+                                           lane_sum, put_lane)
 
 
 def _sq8_kernel(nbr_ref, eval_ref, q_ref, lo_ref, scale_ref, eps_ref,
-                words_ref, ad2_ref, lb2_ref, buf, flags, sem, *, bb: int,
+                words_ref, ad2_ref, lb2_ref, buf, started, sem, *, bb: int,
                 n_lanes: int, kq: int, n_planes: int, d: int):
     rows = kq * n_planes
     lo, scale, eps = lo_ref[...], scale_ref[...], eps_ref[...]
@@ -50,25 +51,20 @@ def _sq8_kernel(nbr_ref, eval_ref, q_ref, lo_ref, scale_ref, eps_ref,
     def plane_sum(x):
         return lane_sum(x, d) if n_planes == 1 else jnp.sum(x, keepdims=True)
 
-    def per_row(r, carry):
+    def consume(r, m, words):
         q = q_ref[pl.ds(r * rows, rows), :]
+        ad2 = slack = 0.0
+        for j in range(n_planes):
+            p = slice(j * kq, (j + 1) * kq)
+            code = ((words >> (8 * j)) & 0xFF).astype(jnp.float32)
+            delta = q[p] - (lo[p] + code * scale[p])
+            ad2 = ad2 + plane_sum(delta * delta)
+            slack = slack + plane_sum(jnp.abs(delta) * eps[p])
+        put_lane(ad2_ref, r, m, ad2)
+        put_lane(lb2_ref, r, m, jnp.maximum(ad2 - 2.0 * slack, 0.0))
 
-        def consume(m, words):
-            ad2 = slack = 0.0
-            for j in range(n_planes):
-                p = slice(j * kq, (j + 1) * kq)
-                code = ((words >> (8 * j)) & 0xFF).astype(jnp.float32)
-                delta = q[p] - (lo[p] + code * scale[p])
-                ad2 = ad2 + plane_sum(delta * delta)
-                slack = slack + plane_sum(jnp.abs(delta) * eps[p])
-            put_lane(ad2_ref, r, m, ad2)
-            put_lane(lb2_ref, r, m, jnp.maximum(ad2 - 2.0 * slack, 0.0))
-
-        gather_lanes(r, n_lanes, kq, lambda m: eval_ref[r, m] != 0, nbr_ref,
-                     words_ref, buf, flags, sem, consume)
-        return carry
-
-    jax.lax.fori_loop(0, bb, per_row, 0)
+    gather_lanes(bb, n_lanes, kq, lambda r, m: eval_ref[r, m] != 0, nbr_ref,
+                 words_ref, buf, started, sem, consume)
 
 
 @functools.partial(jax.jit, static_argnames=("d", "bb", "interpret"))
@@ -104,8 +100,6 @@ def sq8_distance_pallas(nbrs, queries, lo, scale, eps, eval_mask, words, *,
         out_specs=[lanes(), lanes()],
         out_shape=[jax.ShapeDtypeStruct((B, L), jnp.float32),
                    jax.ShapeDtypeStruct((B, L), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((L * kq, 128), jnp.int32),
-                        pltpu.SMEM((L,), jnp.int32),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=gather_scratch(bb, L, kq, jnp.int32),
         interpret=interpret,
     )(nbrs, eval_mask, queries, lo, scale, eps, words)

@@ -231,3 +231,81 @@ def test_fused_expand_sweep(b, m, n, d):
     assert (np.isfinite(np.asarray(d1)) == fin).all()
     np.testing.assert_allclose(np.asarray(d1)[fin], np.asarray(d2)[fin],
                                rtol=1e-5, atol=1e-5)
+
+
+def _block_case(d, seed):
+    """Ids and a fetch mask over B = 3 grid steps + 5 rows, L = 32 lanes:
+    step 0 at the engine's rate (some rows fetch nothing), step 1 fetches
+    every lane, step 2 fetches none, the ragged last step is random.  Lane
+    0 names the same id in every row, row 3 names one id in every lane, and
+    some lanes name the last row, the engine's all-zero pad row."""
+    rng = np.random.default_rng(seed)
+    bb, L, n = ops.ROW_BLOCK, 32, 96
+    B = 3 * bb + 5
+    base = rng.normal(size=(n, d)).astype(np.float32)
+    base[-1] = 0.0
+    ids = rng.integers(0, n - 1, size=(B, L)).astype(np.int32)
+    ids[:, 0] = 7
+    ids[3, :] = 11
+    ids[rng.random((B, L)) < 0.1] = n - 1
+    fetch = (rng.random((B, L)) < 0.1).astype(np.int8)
+    fetch[3 * bb:] = rng.random((5, L)) < 0.5
+    fetch[:, 0] = 1
+    fetch[3, ::2] = 1
+    fetch[[1, 4]] = 0
+    fetch[bb:2 * bb] = 1
+    fetch[2 * bb:3 * bb] = 0
+    qs = rng.normal(size=(B, d)).astype(np.float32)
+    return base, ids, fetch, qs
+
+
+@pytest.mark.parametrize("d", [128, 960])
+@pytest.mark.parametrize("kernel", ["fused_expand", "gather_distance",
+                                    "sq8_distance"])
+def test_block_gather_over_grid_steps(kernel, d):
+    """The block-level row gather (gather_lanes) over several grid steps at
+    k = 1 and k = 8 rows per vector: every lane that should fetch reports
+    the oracle's distance, every other lane +inf, prune masks bit-equal."""
+    base, ids, fetch, qs = _block_case(d, seed=d)
+    B, L = ids.shape
+    nbrs, q = jnp.asarray(ids), jnp.asarray(qs)
+    if kernel == "fused_expand":
+        rng = np.random.default_rng(d + 1)
+        ed = jnp.asarray(rng.uniform(0.5, 3.0, size=(B, L)), jnp.float32)
+        dcq = jnp.asarray(rng.uniform(0.5, 3.0, size=(B, L)), jnp.float32)
+        b2 = jnp.asarray(rng.uniform(2.0, 9.0, size=(B, L)), jnp.float32)
+        elig = fetch & (rng.random((B, L)) < 0.5)
+        elig[ops.ROW_BLOCK:2 * ops.ROW_BLOCK] = 0   # a block fetching all
+        elig = jnp.asarray(elig, jnp.int8)
+        evalm = jnp.asarray(fetch)
+        got, m1 = ops.fused_expand(nbrs, q, ed, dcq, b2, 0.2, base,
+                                   eval_mask=evalm, prune_eligible=elig)
+        exp, m2 = ref.fused_expand_ref(nbrs, q, ed, dcq, b2, 0.2, base,
+                                       eval_mask=evalm, prune_eligible=elig)
+        np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
+        fetched = (fetch != 0) & (np.asarray(m2) == 0)
+        assert 0 < fetched.sum() < fetched.size
+    elif kernel == "gather_distance":
+        got = ops.gather_distance(nbrs, q, base, fetch=jnp.asarray(fetch))
+        exp = ref.gather_distance_ref(nbrs, q, jnp.asarray(base))
+        fetched = fetch != 0
+    else:
+        from repro.quant import sq8 as SQ
+        p = SQ.sq8_train(base)
+        codes = jnp.asarray(SQ.sq8_encode(base, p))
+        sq8_args = (nbrs, q, jnp.asarray(fetch), codes, jnp.asarray(p.lo),
+                    jnp.asarray(p.scale), jnp.asarray(p.eps))
+        got, lb_got = ops.sq8_estimate(*sq8_args)
+        exp, lb_exp = ref.sq8_estimate_ref(*sq8_args)
+        fetched = fetch != 0
+        np.testing.assert_array_equal(np.isfinite(np.asarray(lb_got)),
+                                      fetched)
+        np.testing.assert_allclose(np.asarray(lb_got)[fetched],
+                                   np.asarray(lb_exp)[fetched],
+                                   rtol=1e-5, atol=1e-5)
+    got = np.asarray(got)
+    np.testing.assert_array_equal(np.isfinite(got), fetched)
+    np.testing.assert_allclose(got[fetched], np.asarray(exp)[fetched],
+                               rtol=1e-5, atol=1e-5)
+    bb = ops.ROW_BLOCK
+    assert fetched[bb:2 * bb].all() and not fetched[2 * bb:3 * bb].any()
